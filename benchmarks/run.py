@@ -47,6 +47,8 @@ def main() -> None:
     json_mode = "--json" in args
     smoke = "--smoke" in args
     want = {a for a in args if not a.startswith("-")}
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("# name,us_per_call,derived")
     failed = []
     payloads: dict[str, dict] = {}

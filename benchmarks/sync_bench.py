@@ -38,6 +38,7 @@ element counts and trainer runs for CI.
 """
 from __future__ import annotations
 
+import os
 import time
 
 import jax
@@ -364,7 +365,9 @@ def _overlap_scenario(seed: int, smoke: bool) -> dict:
 def _overlap_distributed(seed: int, smoke: bool) -> dict:
     """The DISTRIBUTED overlap acceptance scenario, in a subprocess
     with 8 forced host devices (this process already initialized jax
-    with one): an ElasticTrainer synced through DistSyncBackend's
+    with one). The child is pinned to the CPU (``JAX_PLATFORMS=cpu``),
+    so it never contends with this process for an accelerator: an
+    ElasticTrainer synced through DistSyncBackend's
     per-hop shard_map collectives under a stable NON-uniform bandwidth
     matrix. Checks: steady-state hidden fraction, exactly one justified
     ring reorder (+ recompile) off the slow link, bit-identity to the
@@ -388,7 +391,7 @@ def _overlap_distributed(seed: int, smoke: bool) -> dict:
         import sys, json
         sys.path.insert(0, {src!r})
         import jax, numpy as np, jax.numpy as jnp
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.configs import CONFIGS
         from repro.core import diloco as dl
         from repro.core.fault_tolerance import ClusterSimulator
@@ -423,7 +426,7 @@ def _overlap_distributed(seed: int, smoke: bool) -> dict:
         m[0, 1] = m[1, 0] = 0.25
         sampler = lambda t: m
 
-        mesh = compat.make_mesh(
+        mesh = make_mesh(
             (K,), ("data",), devices=np.asarray(jax.devices())[:K])
         backend = ts.DistSyncBackend(mesh, "data")
         tr = make_trainer(backend=backend)
@@ -464,7 +467,8 @@ def _overlap_distributed(seed: int, smoke: bool) -> dict:
         }}))
     """).format(src=src, k=k, inner=inner, chunks=chunks, steps=steps)
     out = subprocess.run([sys.executable, "-c", prog],
-                         capture_output=True, text=True, timeout=900)
+                         capture_output=True, text=True, timeout=900,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
     return _json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -542,7 +546,8 @@ def _robust_agg_scenario(seed: int, smoke: bool) -> dict:
 
 def _robust_distributed(seed: int, smoke: bool) -> dict:
     """The DISTRIBUTED half of the robust_agg acceptance, in a
-    subprocess with 8 forced host devices: the same poisoned schedule
+    subprocess with 8 forced host devices, pinned to the CPU like
+    :func:`_overlap_distributed`'s child: the same poisoned schedule
     through DistSyncBackend's per-hop shard_map collectives. The
     admission gates judge host-side float64 copies of the staged rows
     plus the chunk-norm sideband, so the backend must reach the SAME
@@ -563,7 +568,7 @@ def _robust_distributed(seed: int, smoke: bool) -> dict:
         import sys, json
         sys.path.insert(0, {src!r})
         import jax, numpy as np, jax.numpy as jnp
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.configs import CONFIGS
         from repro.core import diloco as dl
         from repro.core import validation as vd
@@ -600,7 +605,7 @@ def _robust_distributed(seed: int, smoke: bool) -> dict:
                                                    events=events()),
                                   sync_backend=backend)
 
-        mesh = compat.make_mesh(
+        mesh = make_mesh(
             (K,), ("data",), devices=np.asarray(jax.devices())[:K])
         tr = make_trainer(backend=ts.DistSyncBackend(mesh, "data"))
         tr.run(STEPS)
@@ -626,7 +631,8 @@ def _robust_distributed(seed: int, smoke: bool) -> dict:
         }}))
     """).format(src=src, inner=inner, steps=steps)
     out = subprocess.run([sys.executable, "-c", prog],
-                         capture_output=True, text=True, timeout=900)
+                         capture_output=True, text=True, timeout=900,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
     return _json.loads(out.stdout.strip().splitlines()[-1])
 

@@ -149,7 +149,7 @@ class ElasticDeviceMesh:
         devices = np.asarray(self.mesh.devices).reshape(-1)[:need]
         new_shape = tuple(new_diloco_size if a == self.diloco_axis
                           else shape[a] for a in axes)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh(new_shape, tuple(axes), devices=devices)
         out = ElasticDeviceMesh(mesh, self.diloco_axis)
         out.slots = SlotAssignment(new_diloco_size)

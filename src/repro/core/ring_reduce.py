@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.kernels import ops as qops
 from repro.kernels.ref import NUM_BUCKETS
 
@@ -193,7 +192,7 @@ def ring_all_reduce(x: jnp.ndarray, axis_name: str,
     with ``x == anchor_flat - theta_flat``) routes the first-hop
     transmit through the fused pseudo-gradient quantizer.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     orig_size = x.shape[-1]
     x = x.astype(jnp.float32)
     if weight is None:
@@ -335,7 +334,12 @@ def _roll1(payload):
 # ``shift`` is a ``ppermute`` along the ring instead of ``jnp.roll``).
 # vmap over one row is bit-identical to the stacked vmap on XLA:CPU
 # (tested), which is what makes the distributed path hop-for-hop
-# bit-identical to the simulator.
+# bit-identical to the simulator there. On TPU, XLA may reduce a k-row
+# batch in another order than one row, so the jnp and int4 codecs'
+# ranges can differ in the last bit between the two paths (measured
+# for the range); the Pallas int8 codec does every reduction inside
+# its kernels in a fixed order, so its rows do not depend on the
+# batch (``chip_smoke.py --four-chips`` checks the anchor).
 
 
 def _rs_hop_rows(s, accs, k: int, chunk: int, bsize: int, nb: int,

@@ -14,11 +14,14 @@ Design notes:
   * grid = (B, Hk, S_blocks); the innermost S dimension revisits the
     same output block (constant index map), so the fp32 accumulator
     lives in the output ref itself — only m and l need scratch.
+  * the cache is read through a free (B, S, Hk * dh) view: head h is
+    the lane-aligned column block h, so a (1, S_BLK, dh) KV block
+    meets the TPU (8, 128) tiling rule whatever Hk is.
   * masks are ONE-HOT-FREE: live cells are found from a broadcasted
     iota of cell indices vs the slot's length (and, for SWA, the ring
     write-cursor arithmetic mirrored from
     ``attention.decode_valid_mask``), never by gathering.
-  * q heads are blocked (1, 1, G, dh) and the cache (1, S_BLK, 1, dh):
+  * q heads are blocked (1, 1, G, dh) and the cache (1, S_BLK, dh):
     the two MXU contractions per block are (G, dh)x(dh, S_BLK) and
     (G, S_BLK)x(S_BLK, dh).
   * dh pads to the 128 lane width, G to the 8-row fp32 sublane tile,
@@ -26,8 +29,8 @@ Design notes:
     dead cell, padded q rows are sliced off on the way out.
 
 Validated against the jnp ``decode_attention`` path in interpret mode
-(this container is CPU-only; TPU is the deployment target) — see
-tests/test_flash_decode.py. Selection follows the repo convention:
+(tests/test_flash_decode.py) and compiled for a described TPU v5e
+(tests/test_tpu_compile.py). Selection follows the repo convention:
 ``impl="pallas" | "jnp"`` (ArchConfig.decode_attn_impl).
 """
 from __future__ import annotations
@@ -66,8 +69,8 @@ def _body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[0, 0]              # (G_p, dh_p)
-    k = k_ref[0, :, 0, :]        # (S_BLK, dh_p)
-    v = v_ref[0, :, 0, :]
+    k = k_ref[0]                 # (S_BLK, dh_p)
+    v = v_ref[0]
     length = len_ref[b]
 
     # which cells of this block are live for this slot (per-slot
@@ -121,8 +124,11 @@ def _flash_decode_call(qg, k, v, length, *, window: int | None,
     dh_p = _pad_to(dh, LANE)
     s_p = _pad_to(s_max, s_blk)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - g), (0, dh_p - dh)))
-    k = jnp.pad(k, ((0, 0), (0, s_p - s_max), (0, 0), (0, dh_p - dh)))
-    v = jnp.pad(v, ((0, 0), (0, s_p - s_max), (0, 0), (0, dh_p - dh)))
+    # (B, S_p, Hk * dh_p): a free view when no padding is needed
+    k = jnp.pad(k, ((0, 0), (0, s_p - s_max), (0, 0), (0, dh_p - dh))
+                ).reshape(b, s_p, hk * dh_p)
+    v = jnp.pad(v, ((0, 0), (0, s_p - s_max), (0, 0), (0, dh_p - dh))
+                ).reshape(b, s_p, hk * dh_p)
 
     kernel = functools.partial(_body, s_blk=s_blk, s_max=s_max,
                                window=window, scale=dh ** -0.5)
@@ -132,10 +138,8 @@ def _flash_decode_call(qg, k, v, length, *, window: int | None,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, g_p, dh_p), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, s_blk, 1, dh_p),
-                         lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, s_blk, 1, dh_p),
-                         lambda b, h, s: (b, s, h, 0)),
+            pl.BlockSpec((1, s_blk, dh_p), lambda b, h, s: (b, s, h)),
+            pl.BlockSpec((1, s_blk, dh_p), lambda b, h, s: (b, s, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, g_p, dh_p),
                                lambda b, h, s: (b, h, 0, 0)),
@@ -171,8 +175,8 @@ def _paged_body(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[0, 0]              # (G_p, dh_p)
-    k = k_ref[0, :, 0, :]        # (blk, dh_p)
-    v = v_ref[0, :, 0, :]
+    k = k_ref[0]                 # (blk, dh_p)
+    v = v_ref[0]
     length = len_ref[b]
 
     cell = s_i * blk + jax.lax.broadcasted_iota(
@@ -224,8 +228,11 @@ def _flash_decode_paged_call(qg, k, v, table, length, *,
     g_p = _pad_to(g, SUBLANE)
     dh_p = _pad_to(dh, LANE)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_p - g), (0, dh_p - dh)))
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, dh_p - dh)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dh_p - dh)))
+    n_blocks = k.shape[0]
+    k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, dh_p - dh))
+                ).reshape(n_blocks, blk, hk * dh_p)
+    v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dh_p - dh))
+                ).reshape(n_blocks, blk, hk * dh_p)
     tbl = jnp.maximum(table, 0).astype(jnp.int32)   # clamp to trash blk
 
     kernel = functools.partial(_paged_body, blk=blk, s_max=s_max,
@@ -238,10 +245,10 @@ def _flash_decode_paged_call(qg, k, v, table, length, *,
                          lambda b, h, s, tbl, ln: (b, h, 0, 0)),
             # the block-gather stage: virtual block s of slot b streams
             # physical pool block tbl[b, s] through VMEM
-            pl.BlockSpec((1, blk, 1, dh_p),
-                         lambda b, h, s, tbl, ln: (tbl[b, s], 0, h, 0)),
-            pl.BlockSpec((1, blk, 1, dh_p),
-                         lambda b, h, s, tbl, ln: (tbl[b, s], 0, h, 0)),
+            pl.BlockSpec((1, blk, dh_p),
+                         lambda b, h, s, tbl, ln: (tbl[b, s], 0, h)),
+            pl.BlockSpec((1, blk, dh_p),
+                         lambda b, h, s, tbl, ln: (tbl[b, s], 0, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, g_p, dh_p),
                                lambda b, h, s, tbl, ln: (b, h, 0, 0)),

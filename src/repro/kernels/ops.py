@@ -22,7 +22,7 @@ def quantize(x: jnp.ndarray, *, impl: str = "pallas") -> Quantized:
     """Paper-faithful int8 quantization (6-sigma clip, bucket-mean codebook)."""
     if impl == "jnp":
         return ref.quantize(x)
-    lo, width = ref.quant_params(x)
+    lo, width = int8_quant.quant_range(x)
     codes, sums, counts = int8_quant.encode_hist(x, lo, width)
     return Quantized(codes, ref.make_codebook(sums, counts, lo, width))
 
@@ -33,15 +33,11 @@ def _quantize_pseudograd(anchor, theta, scale, *, impl: str):
     tf = theta.astype(jnp.float32)
     if impl == "jnp":
         return ref.quantize_pseudograd(af, tf, scale=scale)
-    # lo/width need stats of scale*(anchor - theta). Computed inside this
-    # jit, XLA fuses the subtract/scale straight into the mean/std
-    # reductions, so the pseudo-gradient is never materialized in HBM:
-    # one stats trip over (anchor, theta), then the fused Pallas encode
-    # reads (anchor, theta) once more and emits codes + histogram.
-    pg = af - tf
-    if scale is not None:
-        pg = pg * scale
-    lo, width = ref.quant_params(pg)
+    # the pseudo-gradient scale*(anchor - theta) is never materialized
+    # in HBM: the range kernel reads (anchor, theta) twice (mean, then
+    # centered sum of squares), the fused encode once more and emits
+    # codes + histogram.
+    lo, width = int8_quant.pseudograd_quant_range(af, tf, scale=scale)
     codes, sums, counts = int8_quant.pseudograd_encode_hist(
         anchor, theta, lo, width, scale=scale)
     return Quantized(codes, ref.make_codebook(sums, counts, lo, width))

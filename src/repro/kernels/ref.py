@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 NUM_BUCKETS = 256
 CLIP_SIGMAS = 6.0
-_EPS = 1e-12
+EPS = 1e-12
 
 
 class Quantized(NamedTuple):
@@ -44,7 +44,7 @@ def quant_params(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     sigma = jnp.std(xf)
     half = CLIP_SIGMAS * sigma
     lo = mu - half
-    width = jnp.maximum(2.0 * half / NUM_BUCKETS, _EPS)
+    width = jnp.maximum(2.0 * half / NUM_BUCKETS, EPS)
     return lo, width
 
 
@@ -62,8 +62,9 @@ def bucket_stats(
     xf = x.astype(jnp.float32).reshape(-1)
     c = codes.reshape(-1).astype(jnp.int32)
     sums = jnp.zeros((NUM_BUCKETS,), jnp.float32).at[c].add(xf)
-    counts = jnp.zeros((NUM_BUCKETS,), jnp.float32).at[c].add(1.0)
-    return sums, counts
+    # counted in int32: a float32 count stops growing at 2^24
+    counts = jnp.zeros((NUM_BUCKETS,), jnp.int32).at[c].add(1)
+    return sums, counts.astype(jnp.float32)
 
 
 def make_codebook(

@@ -1,7 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
+
+The dry-run compiles against 512 placeholder host devices, so this
+module pins JAX to the CPU at import (``JAX_PLATFORMS=cpu`` plus
+``--xla_force_host_platform_device_count=512``), and so does every
+per-cell child that ``--all`` starts: none of them ever opens an
+accelerator that another process holds.
 
 For each cell:
   * build the production mesh (16x16 single-pod / 2x16x16 multi-pod),
@@ -20,6 +23,11 @@ Run a single cell:
 Run everything (spawns one subprocess per cell for memory isolation):
     PYTHONPATH=src python -m repro.launch.dryrun --all
 """
+import os
+
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 import argparse
 import json
 import pathlib
@@ -259,7 +267,9 @@ def main():
                 if args.no_sync:
                     cmd.append("--no-sync")
                 print(">>", " ".join(cmd), flush=True)
-                p = subprocess.run(cmd, timeout=3600)
+                p = subprocess.run(
+                    cmd, timeout=3600,
+                    env={**os.environ, "JAX_PLATFORMS": "cpu"})
                 if p.returncode != 0:
                     failures.append((mesh_kind, arch, shape_name))
     print("FAILURES:", failures)

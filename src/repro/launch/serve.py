@@ -111,7 +111,7 @@ def _run_swarm(args, cfg, model, params):
             seed_peer.close()
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
     ap.add_argument("--reduced", action="store_true")
@@ -142,17 +142,17 @@ def main():
     ap.add_argument("--stages", type=int, default=2)
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--timeout", type=float, default=5.0)
-    args = ap.parse_args()
-    if args.engine == "wave" and args.temperature > 0:
-        ap.error("--engine wave is greedy-only; use --engine "
-                 "continuous for --temperature > 0")
+    return ap
 
+
+def build_model(args):
+    """(cfg, model, params) for ``args`` (from :func:`build_parser`):
+    the configured architecture with its decode-attention backend and
+    freshly initialised weights from ``args.seed``."""
     import jax
-    import numpy as np
 
     from repro.configs import get_config
     from repro.models.registry import get_model
-    from repro.serving.engine import Request, make_engine
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -161,28 +161,60 @@ def main():
         cfg = dataclasses.replace(cfg, decode_attn_impl=args.attn_impl)
     model = get_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(args.seed))
-    if args.swarm:
-        _run_swarm(args, cfg, model, params)
-        return
+    return cfg, model, params
+
+
+def build_engine(args, model, params, *, batch_admit: bool = True):
+    """The ``args.engine`` serving engine over ``model``/``params``.
+    ``batch_admit=False`` prefills admissions one request at a time, as
+    the paged engine always does."""
+    from repro.serving.engine import make_engine
+
     engine_kw = dict(batch_slots=args.slots, max_len=args.max_len,
                      bucket_prompts=not args.no_bucket,
                      decode_chunk=args.decode_chunk,
                      top_k=args.top_k, top_p=args.top_p,
-                     seed=args.seed)
+                     seed=args.seed, batch_admit=batch_admit)
     if args.engine == "paged":
         engine_kw.update(block_size=args.block_size,
                          pool_blocks=args.pool_blocks)
-    engine = make_engine(args.engine, model, params, **engine_kw)
+    return make_engine(args.engine, model, params, **engine_kw)
+
+
+def submit_requests(engine, args, vocab: int) -> list:
+    """Queue ``args.requests`` seeded random prompts; returns them."""
+    import numpy as np
+
+    from repro.serving.engine import Request
+
     rng = np.random.default_rng(args.seed)
+    reqs = []
     for i in range(args.requests):
         plen = max(1, int(rng.integers(args.prompt_len // 2,
                                        args.prompt_len + 1)))
-        engine.submit(Request(
+        reqs.append(Request(
             rid=i,
-            prompt=rng.integers(2, cfg.vocab, size=plen).astype(
-                np.int32),
+            prompt=rng.integers(2, vocab, size=plen).astype(np.int32),
             max_new_tokens=args.max_new,
             temperature=args.temperature))
+        engine.submit(reqs[-1])
+    return reqs
+
+
+def main():
+    ap = build_parser()
+    args = ap.parse_args()
+    if args.engine == "wave" and args.temperature > 0:
+        ap.error("--engine wave is greedy-only; use --engine "
+                 "continuous for --temperature > 0")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    cfg, model, params = build_model(args)
+    if args.swarm:
+        _run_swarm(args, cfg, model, params)
+        return
+    engine = build_engine(args, model, params)
+    submit_requests(engine, args, cfg.vocab)
     engine.run_until_drained()
     s = engine.perf_summary()
     print(f"engine={s['engine']} requests={s['requests']} "

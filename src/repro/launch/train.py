@@ -15,10 +15,11 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="intellect-1")
     ap.add_argument("--reduced", action="store_true",
@@ -31,6 +32,10 @@ def main():
     ap.add_argument("--batch-per-worker", type=int, default=4)
     ap.add_argument("--quant", default="int8",
                     choices=["int8", "int4", "fp32"])
+    ap.add_argument("--quant-impl", default="jnp",
+                    choices=["jnp", "pallas"],
+                    help="int8 ring codec: jnp reference or the Pallas "
+                         "kernels (interpret mode off-TPU)")
     ap.add_argument("--overlap", default="none",
                     choices=["none", "delayed"],
                     help="none: outer sync is a barrier between inner "
@@ -75,8 +80,18 @@ def main():
     ap.add_argument("--events", default=None,
                     help='JSON list like [[2,"join",5],[3,"crash",1]]')
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def build_trainer(args, sync_backend=None, *, n_layers: int | None = None,
+                  max_workers: int | None = None):
+    """The ElasticTrainer that ``args`` (from :func:`build_parser`)
+    describe, with freshly initialised weights from ``args.seed``;
+    ``sync_backend`` (``train.step.DistSyncBackend``) runs its outer
+    sync as collectives over a device mesh; ``n_layers`` cuts the
+    model's depth (widths unchanged); ``max_workers`` sets the stacked
+    worker slots (default ``max(2 * workers, workers + 2)``,
+    room for joiners)."""
     import jax
 
     from repro.configs import get_config
@@ -90,6 +105,8 @@ def main():
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
     model = get_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(args.seed))
 
@@ -106,7 +123,8 @@ def main():
     tcfg = TrainerConfig(
         diloco=DiLoCoConfig(
             inner_steps=args.inner_steps or 100, quant=args.quant,
-            outer_lr=args.outer_lr, overlap=args.overlap,
+            quant_impl=args.quant_impl, outer_lr=args.outer_lr,
+            overlap=args.overlap,
             sync_buckets=args.sync_buckets,
             error_feedback=args.error_feedback),
         inner_lr=args.inner_lr, ckpt_dir=args.ckpt_dir,
@@ -114,8 +132,18 @@ def main():
         ckpt_delta_base_every=args.ckpt_base_every,
         ckpt_codec=args.ckpt_codec,
         inner_chunks=args.inner_chunks,
-        max_workers=max(args.workers * 2, args.workers + 2))
-    trainer = ElasticTrainer(model, tcfg, dcfg, params, sim)
+        max_workers=max_workers or max(args.workers * 2,
+                                       args.workers + 2))
+    return ElasticTrainer(model, tcfg, dcfg, params, sim,
+                          sync_backend=sync_backend)
+
+
+def main():
+    args = build_parser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    trainer = build_trainer(args)
+    tcfg = trainer.cfg
 
     if args.join_from:
         peers = []

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import diloco as dl
 from repro.core.sync_engine import SyncEngine, shard_flat_size
 from repro.models import common
@@ -298,7 +297,7 @@ def build_outer_sync(model, plan, mesh, diloco_cfg: dl.DiLoCoConfig,
                 eng = SyncEngine.for_tree(outer_state.anchor)
                 a_flat = eng.flatten(outer_state.anchor)
             new_p, anchor, momentum, residual, ostep, new_a_flat = \
-                compat.shard_map(
+                jax.shard_map(
                     per_worker, mesh=mesh,
                     in_specs=(lead(pspecs), pspecs, pspecs, P(dax),
                               P(), P(), P(dax)),
@@ -337,7 +336,7 @@ def build_outer_sync(model, plan, mesh, diloco_cfg: dl.DiLoCoConfig,
         flat = SyncEngine.for_tree(anchor).flatten(anchor)
         return jnp.pad(flat, (0, 1))          # sentinel element
 
-    flatten_local = compat.shard_map(
+    flatten_local = jax.shard_map(
         _local_flatten, mesh=mesh, in_specs=(pspecs,),
         out_specs=flat_spec, check_vma=False)
 
@@ -362,7 +361,7 @@ def build_outer_sync(model, plan, mesh, diloco_cfg: dl.DiLoCoConfig,
             # updated buffer threads through every later sync
             a_flat = flatten_local(outer_state.anchor)
         new_p, anchor, momentum, residual, ostep, new_a_flat = \
-            compat.shard_map(
+            jax.shard_map(
                 per_worker, mesh=mesh,
                 in_specs=(lead(pspecs), pspecs, pspecs, P(dax), P(),
                           flat_spec, P(dax)),
@@ -521,7 +520,7 @@ class DistSyncPrograms:
             return jax.lax.all_gather(row, lnames, axis=1, tiled=True)
 
         def _sm(f, ins, outs):
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=ins, out_specs=outs,
                 check_vma=False))
 
@@ -619,6 +618,16 @@ class DistRingSyncOp:
     @property
     def pending(self) -> bool:
         return self.hops_done < self.hops_total
+
+    @property
+    def row_shards(self) -> list:
+        """(device, rows) of every shard of the staged accumulator —
+        one row per ring position on its own device; empty once
+        finished."""
+        if self._accs is None:
+            return []
+        return [(s.device, s.data.shape[0])
+                for s in self._accs.addressable_shards]
 
     def step(self) -> bool:
         """Dispatch ONE wire hop (async collective); True iff a hop was

@@ -20,7 +20,7 @@ def _run(body: str) -> str:
         sys.path.insert(0, {src!r})
         import jax, numpy as np, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat
+        from repro.launch.mesh import make_mesh
     """).format(src=_SRC) + textwrap.dedent(body)
     out = subprocess.run([sys.executable, "-c", prog],
                          capture_output=True, text=True, timeout=600)
@@ -47,7 +47,7 @@ def test_distributed_ring_bit_matches_simulation():
                     def f(x):
                         return ring_reduce.ring_all_reduce(
                             x[0], "dp", ring_order=order, cfg=cfg)[None]
-                    dist = jax.jit(compat.shard_map(
+                    dist = jax.jit(jax.shard_map(
                         f, mesh=mesh, in_specs=P("dp"),
                         out_specs=P("dp"), check_vma=False))(xs)
                     sim = ring_reduce.simulate_ring_all_reduce(
@@ -62,7 +62,7 @@ def test_distributed_ring_bit_matches_simulation():
 def test_distributed_outer_sync_matches_simulation():
     out = _run("""
         from repro.core import diloco
-        mesh = compat.make_mesh((8,), ("dp",))
+        mesh = make_mesh((8,), ("dp",))
         rng = np.random.default_rng(3)
         params = {"a": jnp.asarray(rng.normal(size=(8, 6, 7)),
                                    jnp.float32),
@@ -79,7 +79,7 @@ def test_distributed_outer_sync_matches_simulation():
                 jnp.zeros((), jnp.int32))
             np_, _ = diloco.outer_sync(pi, sti, dcfg, "dp")
             return jax.tree.map(lambda x: x[None], np_)
-        dist_p = jax.jit(compat.shard_map(
+        dist_p = jax.jit(jax.shard_map(
             sync, mesh=mesh, in_specs=(P("dp"), P(), P()),
             out_specs=P("dp"), check_vma=False))(
                 params, st.anchor, st.opt.momentum)
@@ -105,7 +105,7 @@ def test_shard_map_train_step_runs_and_reduces_loss():
         from repro.configs.base import ShapeConfig
         import dataclasses
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = CONFIGS["internlm2-1.8b"].reduced()
         shape = ShapeConfig("t", "train", 32, 8)
         plan = make_plan(cfg, shape, {"data": 4, "model": 2})
@@ -153,7 +153,7 @@ def test_replicated_plan_sync_threads_anchor_flat():
         from repro.sharding import make_plan
         from repro.train import step as step_lib
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = CONFIGS["mamba2-130m"].reduced()
         shape = ShapeConfig("t", "train", 32, 8)
         plan = make_plan(cfg, shape, {"data": 4, "model": 2})
@@ -212,7 +212,7 @@ def test_sharded_plan_threads_per_shard_anchor_flat():
         from repro.models.registry import get_model
         from jax.sharding import PartitionSpec as P
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = CONFIGS["internlm2-1.8b"].reduced()
         # reduced() configs all take the inner-DP (replicated) rules;
         # force real TP sharding so the per-shard path is exercised
@@ -287,7 +287,7 @@ def test_full_manual_sync_with_sharded_params():
         from repro.configs.base import ShapeConfig
         from repro.sharding import make_plan
 
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = CONFIGS["internlm2-1.8b"].reduced()
         shape = ShapeConfig("t", "train", 32, 8)
         plan = make_plan(cfg, shape, {"data": 4, "model": 2})
@@ -328,7 +328,7 @@ def test_dist_ring_op_bit_matches_sim_op():
         from repro.core import ring_reduce as rr
         from repro.train import step as ts
         k, size = 4, 37
-        mesh = compat.make_mesh((k,), ("data",),
+        mesh = make_mesh((k,), ("data",),
                                 devices=np.asarray(jax.devices())[:k])
         cfg = rr.RingConfig(quant="int8", buckets=3)
         rng = np.random.default_rng(0)
@@ -384,7 +384,7 @@ def test_hierarchical_ring_matches_per_slice_sim():
         from repro.core import ring_reduce as rr
         from repro.train import step as ts
         k, size = 4, 37
-        mesh = compat.make_mesh((k, 2), ("data", "model"),
+        mesh = make_mesh((k, 2), ("data", "model"),
                                 devices=np.asarray(jax.devices())[:8])
         hier = em.hierarchy(mesh, "data")
         assert hier.split and hier.n_local == 2
@@ -448,7 +448,7 @@ def test_dist_backend_trainer_bit_identical_to_sim():
 
         t_sim = make_trainer()
         hist_sim = t_sim.run(STEPS)
-        mesh = compat.make_mesh((K,), ("data",),
+        mesh = make_mesh((K,), ("data",),
                                 devices=np.asarray(jax.devices())[:K])
         backend = ts.DistSyncBackend(mesh, "data")
         t_dist = make_trainer(backend=backend)

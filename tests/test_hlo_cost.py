@@ -88,12 +88,12 @@ def test_collective_bytes_on_psum():
     """) + textwrap.dedent("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat
+        from repro.launch.mesh import make_mesh
         from repro.analysis.hlo_cost import analyze_hlo
-        mesh = compat.make_mesh((4,), ("x",))
+        mesh = make_mesh((4,), ("x",))
         def f(v):
             return jax.lax.psum(v, "x")
-        g = compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                              check_vma=False)
         c = jax.jit(g).lower(
             jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
