@@ -41,6 +41,7 @@ from repro.core.ring_reduce import (RingConfig, RingSyncOp,
 from repro.core.sync_engine import SyncEngine
 from repro.kernels import ops as qops
 from repro.optim.nesterov import NesterovSGD, NesterovState
+from repro.tracing import span
 
 
 class SyncAbortedError(RuntimeError):
@@ -221,22 +222,23 @@ def _sim_pseudograds(stacked_params, state: OuterState,
     re-flattened the full anchor pytree once per worker inside a vmap);
     per-worker work is a single vmapped flatten + subtract.
     """
-    k = jax.tree.leaves(stacked_params)[0].shape[0]
-    any_params = jax.tree.map(lambda p: p[0], stacked_params)
-    eng = SyncEngine.for_tree(any_params)
+    with span("sync.pseudograds"):
+        k = jax.tree.leaves(stacked_params)[0].shape[0]
+        any_params = jax.tree.map(lambda p: p[0], stacked_params)
+        eng = SyncEngine.for_tree(any_params)
 
-    a_flat = (state.anchor_flat if state.anchor_flat is not None
-              else eng.flatten(state.anchor))
-    p_flats = jax.vmap(eng.flatten)(stacked_params)
-    pgs = a_flat[None, :] - p_flats
-    new_residuals = state.residual
-    if cfg.error_feedback:
-        two_slot = state.residual.ndim == 3
-        res = state.residual[ef_slot] if two_slot else state.residual
-        pgs = pgs + res
-        deqs = jax.vmap(lambda pg: _ef_roundtrip(pg, cfg))(pgs)
-        new_residuals = pgs - deqs
-        pgs = deqs
+        a_flat = (state.anchor_flat if state.anchor_flat is not None
+                  else eng.flatten(state.anchor))
+        p_flats = jax.vmap(eng.flatten)(stacked_params)
+        pgs = a_flat[None, :] - p_flats
+        new_residuals = state.residual
+        if cfg.error_feedback:
+            two_slot = state.residual.ndim == 3
+            res = state.residual[ef_slot] if two_slot else state.residual
+            pgs = pgs + res
+            deqs = jax.vmap(lambda pg: _ef_roundtrip(pg, cfg))(pgs)
+            new_residuals = pgs - deqs
+            pgs = deqs
 
     fused_src = (a_flat, p_flats) if _fused_src_ok(cfg) else None
     return k, any_params, a_flat, pgs, new_residuals, fused_src
@@ -403,14 +405,16 @@ def _finish_apply(handle: OuterSyncHandle, reduced, stacked_params,
                   state: OuterState):
     if handle.aborted:
         raise SyncAbortedError("apply on an aborted sync")
-    any_params = jax.tree.map(lambda p: p[0], stacked_params)
-    res = _commit_residual(state, handle.new_residuals, handle.ef_slot)
-    new_params, new_state = _apply_outer(
-        reduced[0], any_params, state._replace(residual=res),
-        handle.cfg, res, handle.a_flat)
-    stacked_new = jax.tree.map(
-        lambda p: jnp.broadcast_to(p[None], (handle.k,) + p.shape),
-        new_params)
+    with span("sync.apply"):
+        any_params = jax.tree.map(lambda p: p[0], stacked_params)
+        res = _commit_residual(state, handle.new_residuals,
+                               handle.ef_slot)
+        new_params, new_state = _apply_outer(
+            reduced[0], any_params, state._replace(residual=res),
+            handle.cfg, res, handle.a_flat)
+        stacked_new = jax.tree.map(
+            lambda p: jnp.broadcast_to(p[None], (handle.k,) + p.shape),
+            new_params)
     return stacked_new, new_state
 
 

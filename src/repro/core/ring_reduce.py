@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.kernels import ops as qops
 from repro.kernels.ref import NUM_BUCKETS
+from repro.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -502,21 +503,25 @@ def _hop_jit(kind: str, k: int, chunk: int, bsize: int, nb: int,
     key = (kind, k, chunk, bsize, nb, cfg)
     fn = _HOP_JIT.get(key)
     if fn is None:
-        if kind == "rs":
-            fn = jax.jit(lambda s, a: _rs_hop_rows(
-                s, a, k, chunk, bsize, nb, cfg))
-        elif kind == "rs_fused":
-            fn = jax.jit(lambda s, a, af, tp, wp: _rs_hop_rows(
-                s, a, k, chunk, bsize, nb, cfg, (af, tp, wp)))
-        elif kind == "ag_init":
-            fn = jax.jit(lambda a: _ag_init_rows(
-                a, k, chunk, bsize, nb, cfg))
-        elif kind == "ag":
-            fn = jax.jit(lambda s, a, p: _ag_hop_rows(
-                s, a, p, k, chunk, bsize, nb, cfg))
-        else:
+        # named functions: their programs read jit_ring_<kind> in a trace
+        def ring_rs_hop(s, a):
+            return _rs_hop_rows(s, a, k, chunk, bsize, nb, cfg)
+
+        def ring_rs_fused_hop(s, a, af, tp, wp):
+            return _rs_hop_rows(s, a, k, chunk, bsize, nb, cfg,
+                                (af, tp, wp))
+
+        def ring_ag_init(a):
+            return _ag_init_rows(a, k, chunk, bsize, nb, cfg)
+
+        def ring_ag_hop(s, a, p):
+            return _ag_hop_rows(s, a, p, k, chunk, bsize, nb, cfg)
+
+        body = {"rs": ring_rs_hop, "rs_fused": ring_rs_fused_hop,
+                "ag_init": ring_ag_init, "ag": ring_ag_hop}
+        if kind not in body:
             raise ValueError(kind)
-        _HOP_JIT[key] = fn
+        fn = _HOP_JIT[key] = jax.jit(body[kind])
     return fn
 
 
@@ -599,20 +604,24 @@ class RingSyncOp:
         i, k = self.hops_done, self.k
         args = (self.k, self._chunk, self._bsize, self._nb, self.cfg)
         if i < k - 1:
-            if i == 0 and self._fused0:
+            kind = "rs_fused" if i == 0 and self._fused0 else "rs"
+        else:
+            kind = "ag"
+        with span("sync.hop", hop=i, kind=kind):
+            if kind == "rs_fused":
                 self._accs = _hop_jit("rs_fused", *args)(
                     jnp.int32(0), self._accs, self._a_flat,
                     self._t_pos, self._w_pos)
-            else:
+            elif kind == "rs":
                 self._accs = _hop_jit("rs", *args)(
                     jnp.int32(i), self._accs)
-        else:
-            s = i - (k - 1)
-            if s == 0:
-                self._accs, self._payloads = _hop_jit(
-                    "ag_init", *args)(self._accs)
-            self._accs, self._payloads = _hop_jit("ag", *args)(
-                jnp.int32(s), self._accs, self._payloads)
+            else:
+                s = i - (k - 1)
+                if s == 0:
+                    self._accs, self._payloads = _hop_jit(
+                        "ag_init", *args)(self._accs)
+                self._accs, self._payloads = _hop_jit("ag", *args)(
+                    jnp.int32(s), self._accs, self._payloads)
         self.hops_done += 1
         return True
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ShapeConfig
+from repro.tracing import span
 
 MIN_BUCKET = 8        # smallest prompt pad bucket
 NEG_INF = -1e30
@@ -435,7 +436,6 @@ class ContinuousEngine(_EngineBase):
                                   static_argnames=("n",))
         self.stats["decode_chunks"] = 0
         self.stats["prefills"] = 0
-        self.stats["admit_batch_max"] = 0
 
     # -- device-side pieces ---------------------------------------------------
 
@@ -558,14 +558,25 @@ class ContinuousEngine(_EngineBase):
             padded = self._padded_len(len(req.prompt))
             tokens = np.full((1, padded), self.pad_id, np.int32)
             tokens[0, :len(req.prompt)] = req.prompt
-            self.stats["prefill_widths"].add(padded)
-            self.stats["prefills"] += 1
-            logits, sub = self._prefill(
+            logits, sub = self._prefill_call(
+                req.rid, tokens, [len(req.prompt)], self._pcache0)
+            self._prepped.append((req, sub, logits))
+
+    def _prefill_call(self, rid: int, tokens: np.ndarray, plen,
+                      cache):
+        """Dispatch one bucketed prefill of right-padded ``tokens``
+        (rows, width) with per-row prompt lengths ``plen`` into
+        ``cache``, and count it; ``rid`` names the first request.
+        Returns (last-token logits, filled sub-cache)."""
+        width = tokens.shape[1]
+        self.stats["prefill_widths"].add(width)
+        self.stats["prefills"] += 1
+        with span("engine.prefill", rid=rid, width=width):
+            return self._prefill(
                 self.params,
                 {"tokens": jnp.asarray(tokens),
-                 "prompt_len": jnp.asarray([len(req.prompt)], np.int32)},
-                self._pcache0)
-            self._prepped.append((req, sub, logits))
+                 "prompt_len": jnp.asarray(plen, np.int32)},
+                cache)
 
     def _admit_group(self, reqs: list, slots: list) -> None:
         for req in reqs:
@@ -578,15 +589,8 @@ class ContinuousEngine(_EngineBase):
         for i, r in enumerate(reqs):
             tokens[i, :len(r.prompt)] = r.prompt         # RIGHT-pad
             plen[i] = len(r.prompt)
-        self.stats["prefill_widths"].add(padded)
-        self.stats["prefills"] += 1
-        self.stats["admit_batch_max"] = max(
-            self.stats["admit_batch_max"], len(reqs))
-        logits, sub = self._prefill(
-            self.params,
-            {"tokens": jnp.asarray(tokens),
-             "prompt_len": jnp.asarray(plen)},
-            self._pcache(nb))
+        logits, sub = self._prefill_call(reqs[0].rid, tokens, plen,
+                                         self._pcache(nb))
         for i, (req, slot) in enumerate(zip(reqs, slots)):
             sub_i = sub if nb == 1 else tree_take_slot(
                 sub, self._pcache0, i, nb)
@@ -596,12 +600,13 @@ class ContinuousEngine(_EngineBase):
                  logits) -> None:
         """Splice one prefilled request (batch-1 sub-cache + last-token
         logits row) into ``slot`` via the jitted admit step."""
-        out = self._admit_jit(
-            self.cache, self.tokens, self.done, self.remaining,
-            self.temps, self.slot_keys, sub_cache, logits,
-            jnp.int32(slot), self._budget(req) - 1,
-            float(req.temperature), jnp.int32(req.rid))
-        self._finish_install(req, slot, out)
+        with span("engine.install", rid=req.rid, slot=slot):
+            out = self._admit_jit(
+                self.cache, self.tokens, self.done, self.remaining,
+                self.temps, self.slot_keys, sub_cache, logits,
+                jnp.int32(slot), self._budget(req) - 1,
+                float(req.temperature), jnp.int32(req.rid))
+            self._finish_install(req, slot, out)
 
     def _finish_install(self, req: Request, slot: int, out) -> None:
         (self.cache, self.tokens, self.done, self.remaining,
@@ -667,32 +672,37 @@ class ContinuousEngine(_EngineBase):
         """One scheduling quantum: admit into free slots, run one
         decode chunk on device, drain its token block (the single
         device->host transfer), retire finished requests."""
-        self._admit()
-        if not any(r is not None for r in self.active):
-            return 0
-        self._before_chunk()
-        n = self.decode_chunk
-        (self.cache, self.tokens, self.done, self.remaining,
-         self.slot_keys, toks) = self._chunk_jit(
-            self.params, self.cache, self.tokens, self.done,
-            self.remaining, self.temps, self.slot_keys, n=n)
-        if self.overlap_admission:
-            # the chunk above is dispatched but not yet read back:
-            # admission prefills ride the gap
-            self._prep_admissions()
-        lps_np = None
-        if self.capture_logprobs:
-            toks, lps = toks
-            lps_np = np.asarray(lps)   # same chunk-granular sync point
-        toks_np = np.asarray(toks)              # ONE host sync per chunk
-        self.stats["host_syncs"] += 1
-        self.stats["decode_chunks"] += 1
-        self.stats["decode_steps"] += n
-        self.stats["total_slot_steps"] += n * self.slots
-        self.stats["busy_slot_steps"] += int((toks_np >= 0).sum())
-        self._after_chunk(n)
-        self._drain(toks_np, lps_np)
-        return sum(r is not None for r in self.active)
+        with span("engine.step"):
+            with span("engine.admit"):
+                self._admit()
+            if not any(r is not None for r in self.active):
+                return 0
+            self._before_chunk()
+            n = self.decode_chunk
+            with span("engine.decode_chunk"):
+                (self.cache, self.tokens, self.done, self.remaining,
+                 self.slot_keys, toks) = self._chunk_jit(
+                    self.params, self.cache, self.tokens, self.done,
+                    self.remaining, self.temps, self.slot_keys, n=n)
+            if self.overlap_admission:
+                # the chunk above is dispatched but not yet read back:
+                # admission prefills ride the gap
+                self._prep_admissions()
+            with span("engine.readback"):
+                lps_np = None
+                if self.capture_logprobs:
+                    toks, lps = toks
+                    lps_np = np.asarray(lps)   # same chunk-granular sync
+                toks_np = np.asarray(toks)     # ONE host sync per chunk
+            self.stats["host_syncs"] += 1
+            self.stats["decode_chunks"] += 1
+            self.stats["decode_steps"] += n
+            self.stats["total_slot_steps"] += n * self.slots
+            self.stats["busy_slot_steps"] += int((toks_np >= 0).sum())
+            self._after_chunk(n)
+            with span("engine.drain"):
+                self._drain(toks_np, lps_np)
+            return sum(r is not None for r in self.active)
 
 
 # legacy name: the wave engine was the original ServeEngine

@@ -659,13 +659,8 @@ class PagedEngine(ContinuousEngine):
             padded = self._padded_len(w0)
             toks = np.full((1, padded), self.pad_id, np.int32)
             toks[0, :w0] = prompt[:w0]
-            self.stats["prefill_widths"].add(padded)
-            self.stats["prefills"] += 1
-            logits, sub = self._prefill(
-                self.params,
-                {"tokens": jnp.asarray(toks),
-                 "prompt_len": jnp.asarray([w0], np.int32)},
-                self._pcache0)
+            logits, sub = self._prefill_call(req.rid, toks, [w0],
+                                             self._pcache0)
             row_dev = self._row_dev(row)
             if w0 == plen:
                 out = self._paged_admit_jit(

@@ -39,6 +39,7 @@ from repro.core.fault_tolerance import (ClusterSimulator,
                                         CommOverlapLedger, RetryPolicy)
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.optim.adamw import AdamW
+from repro.tracing import span
 
 
 @dataclasses.dataclass
@@ -271,28 +272,54 @@ class ElasticTrainer:
         h = inner_steps or self.cfg.diloco.inner_steps
         global_step = int(self.outer.outer_step) * h
         for t in range(n_outer_steps):
-            plan = self.sim.begin_outer_step(t)
-            # a participant of the in-flight overlapped sync left the
-            # cluster: the partial reduction is torn — fall back to a
-            # synchronous re-reduction over the survivors BEFORE the
-            # dead node's slot is released (we need its slot to zero
-            # its weight)
-            fallback_rec = None
-            if self._inflight is not None and plan.get("sync_torn"):
-                fallback_rec = self._fallback_resync(plan)
-            live_slots = self._sync_membership(plan)
-            active = jnp.asarray(
-                self.slots.live_mask(plan["live"]), jnp.float32)
+            with span("train.outer_step", step=len(self.history)):
+                global_step = self._outer_step(t, h, global_step,
+                                               bandwidth_sampler)
+        # drain: the last boundary's sync is still in flight — apply it
+        # so the returned anchor includes the final phase's progress
+        if self._inflight is not None:
+            with span("train.outer_sync"):
+                self._drain_hops(self._inflight)
+                self.history[-1].setdefault("overlap", {})["drain"] = \
+                    self.comm_ledger.finish_sync()
+                self.params, self.outer = dl.finish_outer_sync_sim(
+                    self._inflight, self.params, self.outer)
+            self._inflight = None
+            self.sim.note_sync_end()
+        if self.snapshotter is not None:
+            self.snapshotter.flush()
+        return self.history
 
+    def _outer_step(self, t: int, h: int, global_step: int,
+                    bandwidth_sampler) -> int:
+        """One outer step of ``run``: membership, feed, inner phase,
+        outer sync, loss readback and bookkeeping. Returns the global
+        inner-step count after it."""
+        plan = self.sim.begin_outer_step(t)
+        # a participant of the in-flight overlapped sync left the
+        # cluster: the partial reduction is torn — fall back to a
+        # synchronous re-reduction over the survivors BEFORE the
+        # dead node's slot is released (we need its slot to zero
+        # its weight)
+        fallback_rec = None
+        if self._inflight is not None and plan.get("sync_torn"):
+            fallback_rec = self._fallback_resync(plan)
+        live_slots = self._sync_membership(plan)
+        active = jnp.asarray(
+            self.slots.live_mask(plan["live"]), jnp.float32)
+
+        with span("train.feed"):
             if self.batch_provider is not None:
                 batches = self.batch_provider(global_step, h, self.k)
             else:
                 batches = jax.tree.map(
                     lambda *xs: jnp.stack(xs),
                     *[self._batches(global_step + i) for i in range(h)])
+        with span("train.inner_phase"):
             losses = self._run_inner_phase(batches, active)
-            global_step += h
+        global_step += h
 
+        with span("train.outer_sync"):
             # fault-harness POISON events corrupt the scheduled nodes'
             # contributions AFTER the inner phase, before the sync —
             # exactly what a faulty peer injects into the ring
@@ -336,9 +363,11 @@ class ElasticTrainer:
                 (self.params, self.outer), _, attempts = \
                     self.retry.run_collective(attempt, plan["live"])
 
+        with span("train.readback"):
             mean_loss = float(losses[-1][
                 jnp.asarray(weights) > 0].mean()) if np.any(
                 np.asarray(weights) > 0) else float("nan")
+        with span("train.bookkeeping"):
             rec = {"outer_step": t, "live": plan["live"],
                    "joined": plan["joined"], "left": plan["left"],
                    "loss": mean_loss, "ring_order": self.ring_order,
@@ -394,19 +423,7 @@ class ElasticTrainer:
                     from repro.checkpointing import save_async
                     save_async(self.cfg.ckpt_dir, global_step, tree,
                                meta)
-        # drain: the last boundary's sync is still in flight — apply it
-        # so the returned anchor includes the final phase's progress
-        if self._inflight is not None:
-            self._drain_hops(self._inflight)
-            self.history[-1].setdefault("overlap", {})["drain"] = \
-                self.comm_ledger.finish_sync()
-            self.params, self.outer = dl.finish_outer_sync_sim(
-                self._inflight, self.params, self.outer)
-            self._inflight = None
-            self.sim.note_sync_end()
-        if self.snapshotter is not None:
-            self.snapshotter.flush()
-        return self.history
+        return global_step
 
     def begin_stream_join(self, peers, *, store_root=None,
                           step: int | None = None,
