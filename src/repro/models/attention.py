@@ -102,22 +102,31 @@ def paged_cache_update(cache: PagedKVCache, k_new: jnp.ndarray,
     unmapped (-1) entry are redirected to the trash block — duplicate
     trash indices are the only scatter collisions, and their values are
     never read."""
-    blk = cache.block_size
-    nb = cache.table.shape[1]
-    s_max = nb * blk
     s_new = k_new.shape[1]
-    start = cache.length % s_max if rolling else cache.length    # (B,)
+    phys, off = _paged_cells(cache.table, cache.length, s_new,
+                             cache.block_size, rolling)
+    k = cache.k.at[phys, off].set(k_new.astype(cache.k.dtype))
+    v = cache.v.at[phys, off].set(v_new.astype(cache.v.dtype))
+    return PagedKVCache(k, v, cache.table, cache.length + s_new)
+
+
+def _paged_cells(table: jnp.ndarray, length: jnp.ndarray, s_new: int,
+                 blk: int, rolling: bool):
+    """Physical (block, offset) of the ``s_new`` cells each slot writes
+    next: (B, S_new) each, past-capacity and unmapped cells on the
+    trash block."""
+    nb = table.shape[1]
+    s_max = nb * blk
+    start = length % s_max if rolling else length                # (B,)
     cells = start[:, None] + jnp.arange(s_new)[None, :]          # (B, S)
     if rolling:
         cells = cells % s_max
     live = cells < s_max
     bi = jnp.clip(cells // blk, 0, nb - 1)
-    phys = jnp.take_along_axis(cache.table, bi, axis=1)          # (B, S)
+    phys = jnp.take_along_axis(table, bi, axis=1)                # (B, S)
     phys = jnp.where(live & (phys >= 0), phys, 0)
     off = jnp.where(live, cells % blk, 0)
-    k = cache.k.at[phys, off].set(k_new.astype(cache.k.dtype))
-    v = cache.v.at[phys, off].set(v_new.astype(cache.v.dtype))
-    return PagedKVCache(k, v, cache.table, cache.length + s_new)
+    return phys, off
 
 
 def _grouped(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
@@ -310,3 +319,37 @@ def cache_update(cache: KVCache, k_new: jnp.ndarray,
     k = jax.vmap(write)(cache.k, k_new, start)
     v = jax.vmap(write)(cache.v, v_new, start)
     return KVCache(k, v, cache.length + s_new)
+
+
+def cache_append_layer(cache, layer, k_new: jnp.ndarray,
+                       v_new: jnp.ndarray, *, rolling: bool = False):
+    """Write one decode step's K/V rows, (B, 1, Hk, dh), into layer
+    ``layer`` of a layer-stacked cache (leaves with a leading L axis).
+
+    Only the B new rows are written, by one scatter into the stacked
+    buffers, so a cache carried through the layer scan is updated in
+    place. Each slot writes where ``cache_update`` would on the layer's
+    own cache: at ``length`` (mod the ring size for SWA), a full slot
+    clamped to the last cell as ``dynamic_update_slice`` clamps; paged
+    caches through the block table (``paged_cache_update``). The stacked
+    lengths are left for the caller to advance once per step.
+
+    Returns ``(cache, layer_cache)``: the stacked cache with the rows
+    written, and layer ``layer`` as ``cache_update`` returns it (its
+    slabs sliced from the stack, lengths advanced by one)."""
+    length = cache.length[layer]                                 # (B,)
+    if isinstance(cache, PagedKVCache):
+        table = cache.table[layer]
+        phys, off = _paged_cells(table, length, 1, cache.block_size,
+                                 rolling)
+        idx = (layer, phys[:, 0], off[:, 0])
+    else:
+        s_max = cache.k.shape[2]
+        start = length % s_max if rolling else length
+        idx = (layer, jnp.arange(length.shape[0]),
+               jnp.minimum(start, s_max - 1))
+    k = cache.k.at[idx].set(k_new[:, 0].astype(cache.k.dtype))
+    v = cache.v.at[idx].set(v_new[:, 0].astype(cache.v.dtype))
+    cache = cache._replace(k=k, v=v)
+    layer_cache = jax.tree.map(lambda x: x[layer], cache)
+    return cache, layer_cache._replace(length=length + 1)

@@ -79,9 +79,13 @@ def init_lm(cfg, key):
 # -- forward ------------------------------------------------------------------
 
 
-def _attn_block(cfg, p, x, *, positions, layer_cache=None,
+def _attn_block(cfg, p, x, *, positions, layer_cache=None, layer=None,
                 cache_update_rolling=False, window, return_kv=False):
-    """Self-attention sublayer. Returns (out, new_cache_or_kv)."""
+    """Self-attention sublayer. Returns (out, new_cache_or_kv).
+
+    In decode, ``layer`` (a traced index) marks ``layer_cache`` as the
+    layer-stacked cache: the new K/V rows are written into layer
+    ``layer`` of it in place, and the stacked cache is returned."""
     hd = _head_dim(cfg)
     h = common.rms_norm(x, p["ln_attn"], cfg.norm_eps)
     b, s, _ = h.shape
@@ -95,11 +99,14 @@ def _attn_block(cfg, p, x, *, positions, layer_cache=None,
     k = common.apply_rope(k, positions, cfg.rope_theta)
 
     if layer_cache is not None and s == 1:      # decode
-        cache = attn.cache_update(layer_cache, k, v,
-                                  rolling=cache_update_rolling)
+        if layer is None:
+            new = cache = attn.cache_update(layer_cache, k, v,
+                                            rolling=cache_update_rolling)
+        else:
+            new, cache = attn.cache_append_layer(
+                layer_cache, layer, k, v, rolling=cache_update_rolling)
         o = attn.decode_attention(q, cache, window=window,
                                   impl=cfg.decode_attn_impl)
-        new = cache
     else:                                        # train / prefill
         o = attn.attention(q, k, v, causal=True, window=window,
                            block_q=cfg.block_q)
@@ -119,12 +126,12 @@ def _ffn_block(cfg, p, x, is_moe: bool, serving: bool = False):
                          p["mlp"]["down"]), {}
 
 
-def _layer(cfg, p, x, *, positions, is_moe, layer_cache=None,
+def _layer(cfg, p, x, *, positions, is_moe, layer_cache=None, layer=None,
            rolling=False, return_kv=False, serving=False):
     x = hint_residual(x)
     a, new_cache = _attn_block(
         cfg, p, x, positions=positions, layer_cache=layer_cache,
-        cache_update_rolling=rolling, window=cfg.sliding_window,
+        layer=layer, cache_update_rolling=rolling, window=cfg.sliding_window,
         return_kv=return_kv)
     x = hint_residual(x + a)
     f, aux = _ffn_block(cfg, p, x, is_moe, serving=serving)
@@ -410,7 +417,14 @@ def stage_decode(cfg, params, inp, cache, *, first: bool, last: bool):
     slots at different depths (continuous batching) each get the right
     RoPE phase — and every stage derives them independently from its
     own cache, which stays consistent across a chain because all
-    stages advance in lockstep."""
+    stages advance in lockstep.
+
+    The stacked cache ``cache["scan"]`` (dense or paged) rides the layer
+    scan as its CARRY: each layer writes its B new K/V rows into its
+    index of the stack in place (``attn.cache_append_layer``) and reads
+    its own slabs back for the attention kernel, and the lengths advance
+    once after the scan. No step rebuilds the stack; under a jit that
+    donates the cache the whole step is in place."""
     x = common.embedding_lookup(params["embed"], inp) if first else inp
     is_moe = cfg.moe is not None
     rolling = cfg.sliding_window is not None
@@ -427,15 +441,19 @@ def stage_decode(cfg, params, inp, cache, *, first: bool, last: bool):
         x = x2
         new_prefix.append(c)
 
-    def body(x, pc):
-        p, c = pc
-        y, new_c, _ = _layer(cfg, p, x, positions=positions,
-                             is_moe=is_moe, layer_cache=c,
-                             rolling=rolling, serving=True)
-        return y, new_c
+    def body(carry, pl):
+        x, c = carry
+        p, layer = pl
+        y, c, _ = _layer(cfg, p, x, positions=positions, is_moe=is_moe,
+                         layer_cache=c, layer=layer, rolling=rolling,
+                         serving=True)
+        return (y, c), None
 
-    x, new_scan = jax.lax.scan(body, x, (params["layers"],
-                                         cache["scan"]))
+    n_scan = jax.tree.leaves(params["layers"])[0].shape[0]
+    (x, new_scan), _ = jax.lax.scan(
+        body, (x, cache["scan"]), (params["layers"], jnp.arange(n_scan)))
+    if new_scan is not None:
+        new_scan = new_scan._replace(length=new_scan.length + 1)
     new_cache = {"scan": new_scan, "prefix": new_prefix}
     if not last:
         return x, new_cache

@@ -9,14 +9,18 @@ Two schedulers share one protocol (submit / step / run_until_drained):
 
 * ``ContinuousEngine`` — slot-level continuous batching with the decode
   loop kept on device:
-    - the (B-slot) cache is allocated ONCE; per-slot cache lengths
+    - the (B-slot) cache is allocated ONCE and stays resident: the
+      decode chunk and the admit step take it DONATED, so each updates
+      it in place (the decode step writes one K/V row per slot per
+      layer, ``transformer.stage_decode``) and the engine's previous
+      ``cache`` handle is dead after every call; per-slot cache lengths
       (``KVCache.length`` is (B,)) let a new request prefill into a
       free slot while the other slots keep decoding — no wave barrier;
     - admission prefills ONE request (batch 1, prompt right-padded to a
       power-of-two bucket so prefill recompiles are capped at
       O(log max_len); exact per-slot semantics via ``prompt_len``) and
       inserts the filled sub-cache into its slot with a jitted
-      tree-wide dynamic_update_slice;
+      tree-wide dynamic_update_slice (in place: the cache is donated);
     - decoding runs N steps as one jitted ``lax.scan`` with ON-DEVICE
       sampling (greedy + temperature/top-k), per-slot EOS/budget done
       flags, and a single device->host transfer of the (N, B) token
@@ -431,9 +435,13 @@ class ContinuousEngine(_EngineBase):
         self.slot_keys = jnp.zeros((self.slots, 2), jnp.uint32)
         self._pending_first: list = [None] * self.slots
         self._prefill = jax.jit(lambda p, b, c: model.prefill(p, b, c))
-        self._admit_jit = jax.jit(self._admit_fn)
-        self._chunk_jit = jax.jit(self._chunk_fn,
-                                  static_argnames=("n",))
+        # the slot cache is donated: a chunk or an install updates it in
+        # place instead of copying it (the prefill templates are not:
+        # every admission reuses them)
+        self._admit_jit = jax.jit(self._admit_fn,
+                                  donate_argnames=("cache",))
+        self._chunk_jit = jax.jit(self._chunk_fn, static_argnames=("n",),
+                                  donate_argnames=("cache",))
         self.stats["decode_chunks"] = 0
         self.stats["prefills"] = 0
 

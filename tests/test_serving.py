@@ -131,10 +131,47 @@ def test_continuous_bit_identical_to_wave_greedy(arch):
         w.submit(r)
     for r in b:
         c.submit(r)
+    busy_at_install = []
+    install = c._install
+
+    def counting_install(req, slot, sub, logits):
+        busy_at_install.append(sum(r is not None for r in c.active))
+        install(req, slot, sub, logits)
+
+    c._install = counting_install
     w.run_until_drained()
     c.run_until_drained()
     for x, y in zip(a, b):
         assert x.out_tokens == y.out_tokens, x.rid
+    # the trace admits requests mid-stream, into the donated cache of
+    # slots that are still decoding
+    assert max(busy_at_install) > 0
+
+
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "mamba2-130m"])
+def test_continuous_engine_donates_cache(arch):
+    """The decode chunk and the install take the slot cache donated:
+    after each, the engine's previous cache buffers are deleted (the
+    update happened in place)."""
+    cfg = CONFIGS[arch].reduced()
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    engine = ContinuousEngine(model, params, batch_slots=2, max_len=64,
+                              decode_chunk=4)
+    engine.submit(Request(0, np.arange(2, 10).astype(np.int32),
+                          max_new_tokens=12))
+    before = jax.tree.leaves(engine.cache)
+    engine.step()                       # install, then a decode chunk
+    assert all(x.is_deleted() for x in before)
+    engine.submit(Request(1, np.arange(3, 7).astype(np.int32),
+                          max_new_tokens=3))
+    before = jax.tree.leaves(engine.cache)
+    engine._admit()                     # install alone
+    assert engine.active[1] is not None
+    assert all(x.is_deleted() for x in before)
+    assert all(not x.is_deleted() for x in
+               jax.tree.leaves((engine.cache, engine._pcache0)))
+    engine.run_until_drained()
 
 
 def test_prefill_widths_are_bucketed(setup):
@@ -285,3 +322,56 @@ def test_per_slot_lengths_mask_independently():
         np.testing.assert_allclose(np.asarray(out[i]),
                                    np.asarray(solo[0]),
                                    rtol=1e-6, atol=1e-6)
+
+
+def _stacked_cache(rng, n_layers, lengths, *, paged):
+    """A layer-stacked cache with random contents: dense (L, B, 8, 2, 4),
+    or paged with 4-cell blocks, 2 per slot, slot 1's second unmapped."""
+    b, hk, dh = len(lengths), 2, 4
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32),
+                               (n_layers, b))
+    if not paged:
+        kv = rng.normal(size=(2, n_layers, b, 8, hk, dh))
+        return attn.KVCache(jnp.asarray(kv[0], jnp.float32),
+                            jnp.asarray(kv[1], jnp.float32), lengths)
+    kv = rng.normal(size=(2, n_layers, 2 * b + 1, 4, hk, dh))
+    table = np.arange(1, 2 * b + 1).reshape(b, 2)
+    table[1, 1] = -1
+    return attn.PagedKVCache(
+        jnp.asarray(kv[0], jnp.float32), jnp.asarray(kv[1], jnp.float32),
+        jnp.broadcast_to(jnp.asarray(table, jnp.int32), (n_layers, b, 2)),
+        lengths)
+
+
+@pytest.mark.parametrize("paged,rolling,lengths", [
+    (False, False, [0, 3, 5, 7]),       # per-slot offsets
+    (False, False, [8, 2, 8, 6]),       # done slots at s_max: clamped
+    (False, True, [7, 8, 9, 21]),       # SWA ring wrap
+    (True, False, [2, 5, 7, 0]),        # block table; slot 1 unmapped
+    (True, True, [9, 3, 12, 17]),       # paged ring wrap
+])
+def test_cache_append_layer_matches_cache_update(paged, rolling, lengths):
+    """Writing a decode step's rows into layer l of the stacked cache
+    gives, bit for bit, what ``cache_update`` gives on that layer's own
+    cache, and leaves every other layer and the stacked lengths alone."""
+    rng = np.random.default_rng(len(lengths) + sum(lengths))
+    n_layers, layer = 3, 1
+    stack = _stacked_cache(rng, n_layers, lengths, paged=paged)
+    k, v = (jnp.asarray(rng.normal(size=(len(lengths), 1, 2, 4)),
+                        jnp.float32) for _ in range(2))
+    append = jax.jit(attn.cache_append_layer, static_argnames="rolling")
+    new, view = append(stack, jnp.int32(layer), k, v, rolling=rolling)
+    want = attn.cache_update(jax.tree.map(lambda x: x[layer], stack),
+                             k, v, rolling=rolling)
+    for got, ref in zip(view, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(new.k[layer]),
+                                  np.asarray(want.k))
+    np.testing.assert_array_equal(np.asarray(new.v[layer]),
+                                  np.asarray(want.v))
+    others = [i for i in range(n_layers) if i != layer]
+    for got, ref in ((new.k, stack.k), (new.v, stack.v)):
+        np.testing.assert_array_equal(np.asarray(got)[others],
+                                      np.asarray(ref)[others])
+    np.testing.assert_array_equal(np.asarray(new.length),
+                                  np.asarray(stack.length))

@@ -10,15 +10,20 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file. Compiles are shapes only; nothing is allocated.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import CONFIGS
 from repro.core import ring_reduce as rr
 from repro.kernels import flash_decode, int8_quant
+from repro.models.registry import get_model
+from repro.serving.engine import make_engine
 
 # intellect-1 FFN width: 58.7 M elements, past float32's exact 2^24
 CHUNK = (4096, 14336)
@@ -107,6 +112,7 @@ def compiled_kernels(monkeypatch):
     sides so no interpret-mode trace is reused here and no compiled-mode
     trace leaks into later CPU tests."""
     monkeypatch.setattr(int8_quant, "_interpret_default", lambda: False)
+    monkeypatch.setattr(flash_decode, "_interpret_default", lambda: False)
     jax.clear_caches()
     yield
     monkeypatch.undo()
@@ -155,3 +161,34 @@ def test_flash_decode_paged_compiles(one_chip):
             q, k, v, t, ln, interpret=False),
         one_chip((B, 1, HQ, DH), jnp.bfloat16), pool, pool,
         one_chip((B, S // blk), jnp.int32), one_chip((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("kind", ["continuous", "paged"])
+def test_decode_chunk_writes_cache_in_place(one_chip, compiled_kernels,
+                                            kind):
+    """The engine's decode chunk, compiled for the chip, updates the
+    stacked KV cache in place: the donated cache aliases the output, and
+    no instruction of the stack's shape copies it or rebuilds it with a
+    dynamic-update-slice (the new rows go in by scatter)."""
+    cfg = dataclasses.replace(CONFIGS["internlm2-1.8b"].reduced(),
+                              head_dim=DH, dtype="bfloat16",
+                              decode_attn_impl="pallas")
+    model = get_model(cfg)
+    params = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    eng = make_engine(kind, model, None, batch_slots=4, max_len=256,
+                      decode_chunk=4)
+    state = (eng.cache, eng.tokens, eng.done, eng.remaining, eng.temps,
+             eng.slot_keys)
+    shapes = jax.tree.map(lambda x: one_chip(x.shape, x.dtype),
+                          (params,) + state)
+    compiled = eng._chunk_jit.lower(*shapes, n=4).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    cache_bytes = sum(x.nbytes for x in jax.tree.leaves(eng.cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    stack = ",".join(map(str, eng.cache["scan"].k.shape))
+    ops = re.findall(r"%(\S+) = \w+\[" + stack + r"\]", text)
+    assert any("scatter" in op for op in ops), ops
+    assert not [op for op in ops if "copy" in op
+                or re.search("dynamic.update.slice", op)], ops
